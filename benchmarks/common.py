@@ -20,6 +20,10 @@ import jax
 from repro.apps.profiles import (APP_STAGE_LATENCY_US,  # noqa: F401
                                  APP_STAGE_RESOURCE, HOP_US, PKT_BITS,
                                  unit_gbps)
+from repro.compile_cache import enable_compile_cache
+
+# Every benchmark imports this module before its first compile.
+enable_compile_cache()
 
 
 def timeit(fn: Callable, *args, iters: int = 10, warmup: int = 3) -> float:

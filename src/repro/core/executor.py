@@ -160,11 +160,9 @@ class ParallelDataPlane:
         self._ring_lanes = 0
         self._ring_proto_key = None
         # compiles = real XLA specializations of the shared dispatch program,
-        # read off jax.jit's own cache (shape-key proxy as fallback on jax
-        # versions without _cache_size). Steady state must show zero growth.
+        # read off jax.jit's own cache. Steady state must show zero growth.
         # by_tenant: per-tenant call/packet attribution when the caller (the
         # service runtime) tags batches with the submitting tenant.
-        self._shape_keys: set = set()
         self.dispatch_stats: Dict[str, Any] = {
             "calls": 0, "compiles": 0, "by_tenant": {}}
         # Observability hooks (ISSUE 7): an optional MetricsRegistry sink for
@@ -181,12 +179,6 @@ class ParallelDataPlane:
             tenant, {"calls": 0, "packets": 0})
         per["calls"] += 1
         per["packets"] += int(packets)
-
-    def _jit_cache_size(self) -> Optional[int]:
-        try:
-            return self._dispatch._cache_size()
-        except AttributeError:
-            return None
 
     def _empty_result(self, batch: PacketBatch) -> PacketBatch:
         """A zero-packet batch with the same pytree structure a processed
@@ -282,7 +274,7 @@ class ParallelDataPlane:
 
         self._ensure_rings(batch, M)
         self.dispatch_stats["calls"] += 1
-        before = self._jit_cache_size()
+        before = self._dispatch._cache_size()
         t0 = time.perf_counter() if self.profile else 0.0
 
         try:
@@ -296,17 +288,9 @@ class ParallelDataPlane:
             self._rings = None
             raise
 
-        after = self._jit_cache_size()
-        if after is not None:
-            grew = after - before
-            self.dispatch_stats["compiles"] += grew
-            compiled = grew > 0
-        else:                                 # proxy: predicted shape keys
-            skey = (B_pad, P_pad, M, N, self._ring_cap, self._ring_proto_key)
-            compiled = skey not in self._shape_keys
-            if compiled:
-                self._shape_keys.add(skey)
-                self.dispatch_stats["compiles"] += 1
+        grew = self._dispatch._cache_size() - before
+        self.dispatch_stats["compiles"] += grew
+        compiled = grew > 0
         # Process-wide compile-cache counters (ISSUE 7): one fused dispatch
         # call == one cache event. miss == jax.jit compiled a fresh shape
         # specialization; hit == warm reuse. Tests assert miss stays 0 after

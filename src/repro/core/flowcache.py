@@ -7,9 +7,9 @@ hits this exact-match table, so steady-state per-batch control cost is
 O(cache misses), not O(unique flows).
 
 Structure: an open-addressed fid -> (pipeline, epoch) table with bounded
-probe windows (``kernels.flow_lookup`` holds the probe math and the three
-lookup backends). The table is mirrored as device arrays: batch lookups run
-as one jitted gather program (Pallas kernel on TPU), and host-side mutations
+probe windows (``kernels.flow_lookup`` holds the probe math and both
+lookup implementations). The table is mirrored as device arrays: batch
+lookups run as one jitted XLA gather program, and host-side mutations
 — inserts, refreshes, deletions — are streamed to the device as bucketed
 scatter updates, so a pure-hit steady state moves nothing host->device.
 
@@ -37,7 +37,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -48,18 +47,13 @@ def _pow2(n: int) -> int:
     return 1 << max(4, int(n - 1).bit_length())
 
 
-def default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
-
-
 @dataclasses.dataclass(frozen=True)
 class FlowCacheConfig:
     capacity: int = 1 << 17        # slots (rounded up to a power of two)
     window: int = 8                # bounded probe window (slots per key)
     idle_ttl: int = 4096           # rounds before an untouched entry expires
     expire_every: int = 256        # rounds between idle-expiry sweeps
-    backend: Optional[str] = None  # numpy | jnp | pallas | interpret
-    block_f: int = 512             # pallas query block
+    backend: str = "jnp"           # jnp (device probe) | numpy (host oracle)
     seed: int = 0                  # clock-eviction tie-break seed
     enabled: bool = True           # False: recency ledger only, no fast path
 
@@ -73,7 +67,7 @@ class FlowCache:
         self.capacity = cap
         self.window = int(self.cfg.window)
         assert self.window <= cap
-        self.backend = self.cfg.backend or default_backend()
+        self.backend = self.cfg.backend
         self.epoch = 0
         # Host-authoritative planes. pid < 0 == empty slot.
         self.key_lo = np.zeros(cap, np.uint32)
@@ -122,15 +116,9 @@ class FlowCache:
         if Fp != F:
             lo = np.concatenate([lo, np.zeros(Fp - F, np.uint32)])
             hi = np.concatenate([hi, np.zeros(Fp - F, np.uint32)])
-        qlo, qhi = jnp.asarray(lo), jnp.asarray(hi)
-        if self.backend in ("pallas", "interpret"):
-            bf = min(self.cfg.block_f, Fp)
-            slot, pid, fresh = fl.lookup_pallas(
-                *planes, qlo, qhi, self.epoch, window=self.window,
-                block_f=bf, interpret=(self.backend == "interpret"))
-        else:
-            slot, pid, fresh = fl.lookup_jnp(*planes, qlo, qhi, self.epoch,
-                                             window=self.window)
+        slot, pid, fresh = fl.lookup_jnp(*planes, jnp.asarray(lo),
+                                         jnp.asarray(hi), self.epoch,
+                                         window=self.window)
         return (np.asarray(slot)[:F].astype(np.int64),
                 np.asarray(pid)[:F], np.asarray(fresh)[:F])
 

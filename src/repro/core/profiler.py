@@ -5,14 +5,9 @@ CPU stage with one resource unit (1 core + 4 GB) and accelerator stages on
 their engines, and record per-stage latency `l_s` / throughput `t_s` and
 whole-pipeline `l_p` / `t_p`.
 
-Two profiling backends:
-  * ``measure``   — wall-clock the jitted stage on this host (used by the
-                    runnable examples/benchmarks; the CPU here plays the role
-                    of the NIC's ARM core);
-  * ``cost_model``— roofline estimate from the stage's compiled
-                    ``cost_analysis()`` against the target chip constants
-                    (used for TPU-target planning in the dry-run, where
-                    wall-clock on CPU would be meaningless).
+``measure_app`` wall-clocks each jitted stage on this host (used by the
+runnable examples/benchmarks; the host plays the role of the NIC's ARM
+core); ``synthetic_profile`` builds a profile from known stage latencies.
 """
 from __future__ import annotations
 
@@ -22,7 +17,6 @@ from typing import Callable, Dict, Optional
 
 import jax
 
-from repro import hw
 from repro.core.graph import MeiliApp, PacketBatch, apply_stage, stage_runner
 
 
@@ -70,17 +64,6 @@ def measure_app(app: MeiliApp, batch: PacketBatch, iters: int = 5) -> AppProfile
     prof = AppProfile(stages=app.stage_names(), l_s=l_s, t_s=t_s, l_p=l_p, t_p=t_p)
     prof._bits = bits
     return prof
-
-
-def cost_model_latency(fn: Callable, *args,
-                       flops_rate: float = hw.PEAK_FLOPS_BF16,
-                       mem_bw: float = hw.HBM_BW) -> float:
-    """Roofline latency estimate of one jitted callable on the target chip."""
-    lowered = jax.jit(fn).lower(*args)
-    cost = lowered.compile().cost_analysis()
-    flops = float(cost.get("flops", 0.0) or 0.0)
-    nbytes = float(cost.get("bytes accessed", 0.0) or 0.0)
-    return max(flops / flops_rate, nbytes / mem_bw)
 
 
 def synthetic_profile(stages, l_s: Dict[str, float], batch_bits: float) -> AppProfile:

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -95,7 +93,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((G, LANES), jnp.float32),
             pltpu.VMEM((G, LANES), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qg, k, v, valid)

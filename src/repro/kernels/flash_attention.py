@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -120,7 +118,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -7,23 +7,20 @@ branch-free vector code — gather the window, compare keys, take the first
 live match — and makes deletion trivial (no tombstones: absence means "not
 in the window", never "probe until an empty slot").
 
-Three implementations of the same probe, pinned bit-identical against each
+Two implementations of the same probe, pinned bit-identical against each
 other and a dict oracle in ``tests/test_flow_lookup.py``:
 
-  * ``lookup_numpy``  — host-side oracle; also what the cache's mutation
-                        path (insert/evict/expire) uses to find slots;
-  * ``lookup_jnp``    — one jitted XLA gather program, the fallback the
-                        fast path uses off-TPU (and what interpret-mode
-                        tests compare the Pallas kernel against);
-  * ``lookup_pallas`` — TPU kernel blocked over queries with the table
-                        planes VMEM-resident (DFA-style row gather, see
-                        ``kernels/dfa_regex.py``). Tables beyond ~2^19
-                        slots would need HBM residency + DMA streaming;
-                        the sim sizes below that.
+  * ``lookup_numpy`` — host-side oracle; also what the cache's mutation
+                       path (insert/evict/expire) uses to find slots;
+  * ``lookup_jnp``   — one jitted XLA gather program over the HBM-resident
+                       table planes: the device probe on every backend. A
+                       2^17-slot table is four 512 KiB planes, which a
+                       VMEM-resident kernel cannot hold once each (cap, 1)
+                       plane pads to 128 lanes.
 
 Keys are int64 flow ids split into two uint32 planes (lo, hi) so no path
-needs x64 mode; the bucket hash is the same wraparound uint32 mix in all
-three. A slot is live iff its pid plane is >= 0. Outputs per query:
+needs x64 mode; the bucket hash is the same wraparound uint32 mix in both.
+A slot is live iff its pid plane is >= 0. Outputs per query:
 
   slot  — table slot holding the key (any epoch), or -1 if absent;
   pid   — cached pipeline id if the entry is live AND epoch-fresh, else -1;
@@ -42,9 +39,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-
-from repro.kernels import compat
 
 # Trace-time compile counters (idiom shared with core.sched_kernel): the
 # Python body of a jitted function runs once per specialization, so steady
@@ -109,7 +103,7 @@ def lookup_numpy(key_lo: np.ndarray, key_hi: np.ndarray, pid: np.ndarray,
     return slot, out_pid, fresh
 
 
-# -- jitted jnp fallback -------------------------------------------------------
+# -- jitted device probe -------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("window",))
 def _lookup_jnp(key_lo, key_hi, pid, epoch, q_lo, q_hi, cur_epoch, *, window):
@@ -135,84 +129,6 @@ def lookup_jnp(key_lo, key_hi, pid, epoch, q_lo, q_hi, cur_epoch: int,
                window: int):
     return _lookup_jnp(key_lo, key_hi, pid, epoch, q_lo, q_hi,
                        jnp.int32(cur_epoch), window=window)
-
-
-# -- Pallas kernel -------------------------------------------------------------
-
-def _lookup_kernel(qlo_ref, qhi_ref, epoch_now_ref, keylo_ref, keyhi_ref,
-                   pid_ref, ep_ref, slot_ref, pid_out_ref, fresh_ref, *,
-                   cap: int, window: int):
-    qlo = qlo_ref[...][:, 0]                                    # (BF,)
-    qhi = qhi_ref[...][:, 0]
-    bf = qlo.shape[0]
-    base = bucket_hash(qlo, qhi) & np.uint32(cap - 1)
-    offs = jax.lax.broadcasted_iota(jnp.uint32, (bf, window), 1)
-    idx = (base[:, None] + offs) & np.uint32(cap - 1)           # (BF, W)
-    flat = idx.reshape(-1).astype(jnp.int32)
-    # DFA-style row gather: table planes are (C, 1) so a 1-D index vector
-    # gathers rows (the only gather shape the TPU lowering supports well).
-    klo = keylo_ref[...][flat].reshape(bf, window)
-    khi = keyhi_ref[...][flat].reshape(bf, window)
-    pids = pid_ref[...][flat].reshape(bf, window)
-    eps = ep_ref[...][flat].reshape(bf, window)
-    match = (klo == qlo[:, None]) & (khi == qhi[:, None]) & (pids >= 0)
-    found = match.sum(axis=1) > 0
-    first = jnp.argmax(match, axis=1)
-    idx_i = idx.astype(jnp.int32)
-    slot = jnp.where(found, jnp.take_along_axis(idx_i, first[:, None], 1)[:, 0],
-                     -1)
-    mpid = jnp.take_along_axis(pids, first[:, None], 1)[:, 0]
-    mep = jnp.take_along_axis(eps, first[:, None], 1)[:, 0]
-    fresh = found & (mep == epoch_now_ref[0, 0])
-    slot_ref[...] = slot[:, None]
-    pid_out_ref[...] = jnp.where(fresh, mpid, -1)[:, None]
-    fresh_ref[...] = fresh[:, None].astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("window", "block_f", "interpret"))
-def _lookup_pallas(key_lo, key_hi, pid, epoch, q_lo, q_hi, cur_epoch, *,
-                   window, block_f, interpret):
-    _count_trace("flow_lookup_pallas")
-    cap = key_lo.shape[0]
-    F = q_lo.shape[0]
-    bf = min(block_f, F)
-    assert F % bf == 0, (F, bf)
-    kernel = functools.partial(_lookup_kernel, cap=cap, window=window)
-    slot, mpid, fresh = pl.pallas_call(
-        kernel,
-        grid=(F // bf,),
-        in_specs=[
-            pl.BlockSpec((bf, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bf, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((cap, 1), lambda i: (0, 0)),
-            pl.BlockSpec((cap, 1), lambda i: (0, 0)),
-            pl.BlockSpec((cap, 1), lambda i: (0, 0)),
-            pl.BlockSpec((cap, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bf, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bf, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bf, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((F, 1), jnp.int32),
-            jax.ShapeDtypeStruct((F, 1), jnp.int32),
-            jax.ShapeDtypeStruct((F, 1), jnp.int32),
-        ],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel",)),
-        interpret=interpret,
-    )(q_lo[:, None], q_hi[:, None], cur_epoch,
-      key_lo[:, None], key_hi[:, None], pid[:, None], epoch[:, None])
-    return slot[:, 0], mpid[:, 0], fresh[:, 0] != 0
-
-
-def lookup_pallas(key_lo, key_hi, pid, epoch, q_lo, q_hi, cur_epoch: int,
-                  window: int, block_f: int = 512, interpret: bool = False):
-    return _lookup_pallas(key_lo, key_hi, pid, epoch, q_lo, q_hi,
-                          jnp.full((1, 1), cur_epoch, jnp.int32),
-                          window=window, block_f=block_f, interpret=interpret)
 
 
 # -- incremental device-table maintenance -------------------------------------

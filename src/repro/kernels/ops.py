@@ -277,9 +277,8 @@ def cipher(words, key, *, impl: Optional[str] = None, block_b: int = 256):
                               interpret=(impl == "interpret"))
 
 
-def digest(words, key, *, impl: Optional[str] = None, block_b: int = 256):
+def digest(words, key, *, impl: Optional[str] = None):
     impl = impl or default_impl()
     if impl in ("ref", "blocked"):
         return _ref.keyed_hash(words, key)
-    return _crypto.keyed_hash(words, key, block_b=block_b,
-                              interpret=(impl == "interpret"))
+    return _crypto.keyed_hash(words, key, interpret=(impl == "interpret"))

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _ssd_kernel(x_ref, la_ref, b_ref, c_ref, y_ref, hout_ref, h_ref, *,
                 num_chunks: int):
@@ -102,7 +100,7 @@ def ssd_scan(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray, *,
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, la, b, c)

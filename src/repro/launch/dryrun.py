@@ -22,7 +22,7 @@ import traceback
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import AxisType, NamedSharding, PartitionSpec
 
 from repro.configs import ARCHS, SHAPES, get_arch
 from repro.configs.base import SUBQUADRATIC, skipped_cells
@@ -40,7 +40,8 @@ from repro.parallel.sharding import (rules_for, set_activation_sharding,
 def _mesh_for(kind: str):
     if kind == "single":
         devs = jax.devices()[:256]
-        return jax.make_mesh((16, 16), ("data", "model"), devices=devs)
+        return jax.make_mesh((16, 16), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2, devices=devs)
     return make_production_mesh(multi_pod=True)
 
 

@@ -178,8 +178,8 @@ DEFAULT_MIX = (
 BACKUP_NICS = ("bf1-0", "bf1-1", "bf1-2", "bf1-3")
 
 
-def default_tenant_mix(impl: Optional[str] = "ref") -> List[TenantSpec]:
-    apps = ALL_APPS(impl=impl)
+def default_tenant_mix() -> List[TenantSpec]:
+    apps = ALL_APPS()
     mix = []
     for i, (key, gbps, p99, prio) in enumerate(DEFAULT_MIX):
         mix.append(TenantSpec(
@@ -194,13 +194,12 @@ def contracts(mix: List[TenantSpec]) -> Dict[str, float]:
     return {s.name: s.sla.target_gbps for s in mix}
 
 
-def churn_tenant_mix(ticks: int = 96, impl: Optional[str] = "ref"
-                     ) -> List[TenantSpec]:
+def churn_tenant_mix(ticks: int = 96) -> List[TenantSpec]:
     """A churn-heavy variant of the evaluation mix: two first-wave tenants
     depart mid-run and a second wave arrives into the holes they leave.
     Deterministic; arrival/departure ticks scale with the run length so the
     same mix works for smoke and full benchmark runs."""
-    mix = default_tenant_mix(impl=impl)
+    mix = default_tenant_mix()
     # First wave: ICG and FM leave, opening mid-run holes in the packing.
     mix[1] = dataclasses.replace(mix[1], depart_tick=max(2, int(0.30 * ticks)))
     mix[4] = dataclasses.replace(mix[4], depart_tick=max(3, int(0.45 * ticks)))
@@ -212,7 +211,7 @@ def churn_tenant_mix(ticks: int = 96, impl: Optional[str] = "ref"
         ("LLB", 8.0, 300e-6, 2, 0.60),
     )
     for i, (key, gbps, p99, prio, frac) in enumerate(wave2):
-        apps = ALL_APPS(impl=impl)
+        apps = ALL_APPS()
         mix.append(TenantSpec(
             name=f"t-{key.lower()}-w2", app=apps[key],
             profile=paper_profile(key),

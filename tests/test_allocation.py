@@ -1,6 +1,6 @@
 """Algorithm 2/3 (locality-aware allocation) — unit + property tests."""
 import pytest
-from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import pool as pool_mod
 from repro.core.allocation import commit, release, resource_alloc

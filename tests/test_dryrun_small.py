@@ -15,7 +15,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ["JAX_PLATFORMS"] = "cpu"   # never probe for TPU in the subprocess
 import json, jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import AxisType, NamedSharding, PartitionSpec
 from repro.configs import get_arch
 from repro.configs.base import ShapeConfig
 from repro.models import build
@@ -28,7 +28,8 @@ from repro.parallel.sharding import default_rules
 
 cfg = get_arch("olmo-1b").reduced()
 model = build(cfg)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 rules = default_rules()
 out = {}
 
@@ -45,10 +46,7 @@ comp = jax.jit(step_fn, in_shardings=(p_shard, o_shard, b_shard, sc),
                donate_argnums=(0, 1)).lower(
     p_struct, o_struct, b_struct,
     jax.ShapeDtypeStruct((), jnp.int32)).compile()
-ca = comp.cost_analysis()
-if isinstance(ca, (list, tuple)):      # older jax returns one dict per device
-    ca = ca[0] if ca else {}
-out["train_flops"] = float(ca.get("flops", 0))
+out["train_flops"] = float(comp.cost_analysis().get("flops", 0))
 out["train_coll"] = rl.collective_bytes(comp.as_text())["total"]
 
 # decode lower+compile

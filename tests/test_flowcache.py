@@ -12,13 +12,12 @@ from __future__ import annotations
 
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.apps.packets import pareto_flow_weights, synth_packets_weighted
 from repro.core.flowcache import FlowCache, FlowCacheConfig
 from repro.core.orchestrator import TrafficOrchestrator
 from repro.obs.trace import DecisionTrace
-
-from tests._hypothesis_shim import given, settings, st
 
 NPIPE = 4
 

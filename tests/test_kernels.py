@@ -149,6 +149,20 @@ def test_dfa_kernel_vs_ref(B, L, block_b):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def test_dfa_kernel_table_over_256_states():
+    """Past 256 states the kernel splits the table into two bf16 planes."""
+    pats = ["".join(chr(97 + int(c)) for c in RNG.integers(0, 4, size=6))
+            for _ in range(80)]
+    table, out = ref.build_aho_corasick(pats)
+    assert table.shape[0] > 256
+    pay = jnp.asarray(RNG.integers(97, 101, size=(16, 200)).astype(np.uint8))
+    length = jnp.asarray(RNG.integers(1, 201, size=(16,)), jnp.int32)
+    want = ref.dfa_scan(pay, length, jnp.asarray(table), jnp.asarray(out))
+    got = ops.regex_scan(pay, length, table, out, impl="interpret", block_b=8)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(want.sum()) > 0
+
+
 def test_dfa_respects_length():
     table, out = ref.build_aho_corasick(["xy"])
     pay = jnp.asarray(np.frombuffer(b"xyxyxy", np.uint8)[None])
@@ -178,10 +192,11 @@ def test_cipher_key_sensitivity():
     assert not np.array_equal(np.asarray(c1), np.asarray(c2))
 
 
-def test_hash_kernel_matches():
-    w = jnp.asarray(RNG.integers(0, 2 ** 32, size=(8, 32),
+@pytest.mark.parametrize("B,W", [(8, 32), (2100, 5)])
+def test_hash_kernel_matches(B, W):
+    w = jnp.asarray(RNG.integers(0, 2 ** 32, size=(B, W),
                                  dtype=np.uint64).astype(np.uint32))
-    key = jnp.asarray([9, 9, 9, 9], jnp.uint32)
+    key = jnp.asarray([9, 8, 7, 6], jnp.uint32)
     np.testing.assert_array_equal(
-        np.asarray(ops.digest(w, key, impl="interpret", block_b=4)),
+        np.asarray(ops.digest(w, key, impl="interpret")),
         np.asarray(ref.keyed_hash(w, key)))

@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.apps.packets import synth_packets
 from repro.core.orchestrator import SubBatch, TrafficOrchestrator, flow_ids

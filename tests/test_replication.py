@@ -2,7 +2,7 @@
 import math
 
 import pytest
-from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import replication as repl
 from repro.core import sim

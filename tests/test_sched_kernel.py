@@ -2,24 +2,22 @@
 ``core.sched_kernel`` is checked against the pinned scalar reference in
 ``core.qos`` / ``service.telemetry`` over randomized inputs.
 
-Hypothesis is optional (see ``tests/_hypothesis_shim``): the ``@given``
-variants skip without it, so each property also runs as a seeded-random
-loop that executes everywhere. f32 kernel vs f64 scalar means comparisons
-are tolerance-based, never bit-exact — the tolerance is the contract.
+Each property runs both as a ``@given`` hypothesis test and as a
+seeded-random loop. f32 kernel vs f64 scalar means comparisons are
+tolerance-based, never bit-exact — the tolerance is the contract.
 """
 from __future__ import annotations
 
 import random
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.core import sched_kernel as sk
 from repro.core.qos import ResourceGovernor, TenantQuota
-from tests._hypothesis_shim import given, settings, st
-
-import jax.numpy as jnp
 
 # Relative tolerance for f32 kernel vs f64 scalar on O(1e4)-byte budgets.
 RTOL = 5e-4

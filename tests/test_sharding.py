@@ -2,7 +2,7 @@
 import jax
 import numpy as np
 import pytest
-from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec
 
 from repro.parallel.sharding import default_rules, spec_for

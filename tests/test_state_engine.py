@@ -1,7 +1,7 @@
 """State engine: operators, access patterns, bounded-inconsistency sync."""
 import numpy as np
 import pytest
-from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.state_engine import (FULL_ACCESS, NON_EXTERNAL_WRITE,
                                      LinkedHashTable, StateService,
