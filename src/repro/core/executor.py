@@ -52,6 +52,7 @@ from repro.core.graph import (MeiliApp, PacketBatch, _cache_stats,
 from repro.core.orchestrator import SubBatch, TrafficOrchestrator
 from repro.core.ringbuffer import Ring, make_rings, pop_many, push_many
 from repro.core import replication as repl
+from repro.obs.spans import span
 
 MIN_BUCKET = 16
 
@@ -130,7 +131,7 @@ class ParallelDataPlane:
                  latencies: Optional[Dict[str, float]] = None,
                  capacity_per_pipeline: float = 256.0,
                  ring_capacity: int = 4096,
-                 metrics=None, profile: bool = False,
+                 metrics=None,
                  flow_cache: bool = True, flow_cache_config=None,
                  table_cap: Optional[int] = None, trace=None):
         if num_pipelines is None:
@@ -165,12 +166,8 @@ class ParallelDataPlane:
         # service runtime) tags batches with the submitting tenant.
         self.dispatch_stats: Dict[str, Any] = {
             "calls": 0, "compiles": 0, "by_tenant": {}}
-        # Observability hooks (ISSUE 7): an optional MetricsRegistry sink for
-        # call/compile counters, and a profile flag that times every fused
-        # dispatch to completion (block_until_ready) into a histogram —
-        # OFF by default because blocking serializes the device queue.
+        # An optional MetricsRegistry sink for call/compile counters.
         self.metrics = metrics
-        self.profile = profile
 
     def _tag_tenant(self, tenant: Optional[str], packets: int) -> None:
         if tenant is None:
@@ -238,6 +235,47 @@ class ParallelDataPlane:
         self._tag_tenant(tenant, proc.size)
         if proc.size == 0:
             return self._empty_result(batch)
+        with span("meili.dispatch.index"):
+            padded, *index = self._index(batch, assign, proc)
+        self.dispatch_stats["calls"] += 1
+        before = self._dispatch._cache_size()
+        with span("meili.dispatch.enqueue"):
+            try:
+                self._rings, out = self._dispatch(self._rings, padded, *index)
+            except BaseException:
+                # The ring was donated to the failed call and may already be
+                # invalidated; drop it so the next round reallocates instead
+                # of dying on deleted buffers forever.
+                self._rings = None
+                raise
+
+        grew = self._dispatch._cache_size() - before
+        self.dispatch_stats["compiles"] += grew
+        compiled = grew > 0
+        # Process-wide compile-cache counters: one fused dispatch
+        # call == one cache event. miss == jax.jit compiled a fresh shape
+        # specialization; hit == warm reuse. Tests assert miss stays 0 after
+        # warmup (zero steady-state recompiles, now an observable).
+        dstats = _cache_stats("dispatch")
+        dstats["miss" if compiled else "hit"] += 1
+        if self.metrics is not None:
+            self._sync_cache_metrics()
+            self.metrics.counter("dataplane_dispatch_calls_total",
+                                 app=self.app.name).inc()
+            if self.dispatch_stats["compiles"] > 0:
+                self.metrics.gauge("dataplane_dispatch_compiles",
+                                   app=self.app.name).set(
+                                       self.dispatch_stats["compiles"])
+        P = proc.size
+        if _bucket(P) != P:
+            out = jax.tree.map(lambda a: a[:P], out)
+        return out
+
+    def _index(self, batch: PacketBatch, assign: np.ndarray,
+               proc: np.ndarray) -> Tuple:
+        """The dispatch's arguments after the rings: the ingress batch
+        padded to its bucket, then ``perm``, ``counts`` and ``out_idx``
+        (host arrays, copied to the device by the dispatch call)."""
         lanes_of = assign[proc]
         N = len(self.to.pipelines)
         counts = np.bincount(lanes_of, minlength=N).astype(np.int32)
@@ -254,7 +292,7 @@ class ParallelDataPlane:
         ranks = np.arange(proc.size) - starts[lanes_sorted]
         perm = np.zeros((N, M), np.int32)      # pad slots gather row 0 (masked)
         perm[lanes_sorted, ranks] = proc[order]
-        out_idx = np.empty(proc.size, np.int64)
+        out_idx = np.empty(proc.size, np.int32)
         out_idx[order] = lanes_sorted * M + ranks
 
         # Every jit-facing shape is bucketed — M above, and here the ingress
@@ -270,50 +308,9 @@ class ParallelDataPlane:
         P = proc.size
         P_pad = _bucket(P)
         if P_pad != P:
-            out_idx = np.concatenate([out_idx, np.zeros(P_pad - P, np.int64)])
-
+            out_idx = np.concatenate([out_idx, np.zeros(P_pad - P, np.int32)])
         self._ensure_rings(batch, M)
-        self.dispatch_stats["calls"] += 1
-        before = self._dispatch._cache_size()
-        t0 = time.perf_counter() if self.profile else 0.0
-
-        try:
-            self._rings, out = self._dispatch(
-                self._rings, batch, jnp.asarray(perm), jnp.asarray(counts),
-                jnp.asarray(out_idx))
-        except BaseException:
-            # The ring was donated to the failed call and may already be
-            # invalidated; drop it so the next round reallocates instead of
-            # dying on deleted buffers forever.
-            self._rings = None
-            raise
-
-        grew = self._dispatch._cache_size() - before
-        self.dispatch_stats["compiles"] += grew
-        compiled = grew > 0
-        # Process-wide compile-cache counters (ISSUE 7): one fused dispatch
-        # call == one cache event. miss == jax.jit compiled a fresh shape
-        # specialization; hit == warm reuse. Tests assert miss stays 0 after
-        # warmup (zero steady-state recompiles, now an observable).
-        dstats = _cache_stats("dispatch")
-        dstats["miss" if compiled else "hit"] += 1
-        if self.profile:
-            jax.block_until_ready(out)
-            us = (time.perf_counter() - t0) * 1e6
-            if self.metrics is not None:
-                self.metrics.histogram("dataplane_dispatch_us",
-                                       app=self.app.name).observe(us)
-        if self.metrics is not None:
-            self._sync_cache_metrics()
-            self.metrics.counter("dataplane_dispatch_calls_total",
-                                 app=self.app.name).inc()
-            if self.dispatch_stats["compiles"] > 0:
-                self.metrics.gauge("dataplane_dispatch_compiles",
-                                   app=self.app.name).set(
-                                       self.dispatch_stats["compiles"])
-        if P_pad != P:
-            out = jax.tree.map(lambda a: a[:P], out)
-        return out
+        return batch, perm, counts, out_idx
 
     # -- per-stage device profiling (ISSUE 7) ----------------------------------
     def profile_stages(self, batch: PacketBatch,

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import flow_lookup as fl
+from repro.obs.spans import span
 
 
 def _pow2(n: int) -> int:
@@ -110,17 +111,18 @@ class FlowCache:
         if self.backend == "numpy" or fids.size == 0:
             return fl.lookup_numpy(self.key_lo, self.key_hi, self.pid,
                                    self.ep, lo, hi, self.epoch, self.window)
-        planes = self._device_planes()
-        F = fids.size
-        Fp = _pow2(F)
-        if Fp != F:
-            lo = np.concatenate([lo, np.zeros(Fp - F, np.uint32)])
-            hi = np.concatenate([hi, np.zeros(Fp - F, np.uint32)])
-        slot, pid, fresh = fl.lookup_jnp(*planes, jnp.asarray(lo),
-                                         jnp.asarray(hi), self.epoch,
-                                         window=self.window)
-        return (np.asarray(slot)[:F].astype(np.int64),
-                np.asarray(pid)[:F], np.asarray(fresh)[:F])
+        with span("meili.to.probe"):
+            planes = self._device_planes()
+            F = fids.size
+            Fp = _pow2(F)
+            if Fp != F:
+                lo = np.concatenate([lo, np.zeros(Fp - F, np.uint32)])
+                hi = np.concatenate([hi, np.zeros(Fp - F, np.uint32)])
+            slot, pid, fresh = fl.lookup_jnp(*planes, jnp.asarray(lo),
+                                             jnp.asarray(hi), self.epoch,
+                                             window=self.window)
+            return (np.asarray(slot)[:F].astype(np.int64),
+                    np.asarray(pid)[:F], np.asarray(fresh)[:F])
 
     def _device_planes(self) -> Tuple:
         if self._planes is None or self._full_upload:

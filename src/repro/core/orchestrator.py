@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import PacketBatch
+from repro.obs.spans import span
 
 # Sentinel values in the per-packet assign array.
 ASSIGN_NONE = -1      # not yet assigned (internal)
@@ -136,17 +137,24 @@ class TrafficOrchestrator:
              the highest-capacity active pipeline (load tracks the overload
              so ``utilization`` sees it).
         """
-        fids = flow_ids(batch)
-        B = len(fids)
         self._round += 1
+        with span("meili.to.assign", round=self._round):
+            return self._assign(batch, tenant)
+
+    def _assign(self, batch: PacketBatch, tenant: str | None) -> np.ndarray:
+        """``partition_assign``'s body, inside its ``meili.to.assign`` span;
+        the round is already advanced."""
+        with span("meili.to.flow_ids"):
+            fids = flow_ids(batch)
+            uniq, first_pos, inverse, counts = np.unique(
+                fids, return_index=True, return_inverse=True,
+                return_counts=True)
+        B = len(fids)
         for p in self.pipelines:
             p.load = 0.0
         assign = np.full(B, ASSIGN_NONE, dtype=np.int64)
         if B == 0:
             return assign
-
-        uniq, first_pos, inverse, counts = np.unique(
-            fids, return_index=True, return_inverse=True, return_counts=True)
 
         cache = self.flow_cache
         done = False
@@ -162,9 +170,11 @@ class TrafficOrchestrator:
                 self.fast_stats["slow_batches"] += 1
             self._slow_assign(assign, uniq, first_pos, by_flow, group_start,
                               tenant)
-            if cache is not None:
-                self._record_slow(assign, uniq, by_flow, group_start)
-        self._maintain()
+            with span("meili.to.commit"):
+                if cache is not None:
+                    self._record_slow(assign, uniq, by_flow, group_start)
+        with span("meili.to.maintain"):
+            self._maintain()
 
         # Buffer packets of halted (migrating) flows (scan only the halted
         # subset, not the batch, once per flow).
@@ -202,51 +212,54 @@ class TrafficOrchestrator:
             load[pid] += take
             return off + take
 
-        # Flows in first-appearance order — the order the per-packet walk
-        # would discover them.
-        for u in np.argsort(first_pos, kind="stable"):
-            f = int(uniq[u])
-            seg = by_flow[group_start[u]:group_start[u + 1]]
-            if f in self.halted_flows:
-                assign[seg] = ASSIGN_HALTED
-                continue
-            # Raised lazily: a batch made entirely of halted-flow packets
-            # must buffer cleanly even with every pipeline scaled down.
-            if not active.any():
-                raise ValueError("partition: no active pipelines")
-            home = self.flow_table.get(f)
-            was_new = home is None
-            off = 0
-            if home is not None and active[home]:
-                off = grab(home, seg, off)
-            if off < seg.size:
-                for spid in self.spill_table.get(f, ()):
-                    if active[spid]:
-                        off = grab(spid, seg, off)
-                    if off == seg.size:
-                        break
-            while off < seg.size:
-                pid = int(np.argmax(np.where(active, avail, -1.0)))
-                if avail[pid] >= 1.0:
-                    off = grab(pid, seg, off)
-                else:
-                    # Every active pipeline saturated: overload the largest.
-                    pid = int(np.argmax(np.where(active, cap, -1.0)))
-                    assign[seg[off:]] = pid
-                    load[pid] += seg.size - off
-                    off = seg.size
-                if home is None:
-                    self.flow_table[f] = pid   # first pipeline stays "home"
-                    home = pid
-                elif pid != home:
-                    sp = self.spill_table.setdefault(f, [])
-                    if pid not in sp:
-                        sp.append(pid)
-            if was_new and self.trace is not None and traced < PLACE_TRACE_CAP:
-                traced += 1
-                self.trace.event("slow_path_place", tenant=tenant,
-                                 flow=f, pipeline=int(home),
-                                 reason="new_flow")
+        with span("meili.to.miss_loop"):
+            # Flows in first-appearance order — the order the per-packet walk
+            # would discover them.
+            for u in np.argsort(first_pos, kind="stable"):
+                f = int(uniq[u])
+                seg = by_flow[group_start[u]:group_start[u + 1]]
+                if f in self.halted_flows:
+                    assign[seg] = ASSIGN_HALTED
+                    continue
+                # Raised lazily: a batch made entirely of halted-flow packets
+                # must buffer cleanly even with every pipeline scaled down.
+                if not active.any():
+                    raise ValueError("partition: no active pipelines")
+                home = self.flow_table.get(f)
+                was_new = home is None
+                off = 0
+                if home is not None and active[home]:
+                    off = grab(home, seg, off)
+                if off < seg.size:
+                    for spid in self.spill_table.get(f, ()):
+                        if active[spid]:
+                            off = grab(spid, seg, off)
+                        if off == seg.size:
+                            break
+                while off < seg.size:
+                    pid = int(np.argmax(np.where(active, avail, -1.0)))
+                    if avail[pid] >= 1.0:
+                        off = grab(pid, seg, off)
+                    else:
+                        # Every active pipeline saturated: overload the
+                        # largest.
+                        pid = int(np.argmax(np.where(active, cap, -1.0)))
+                        assign[seg[off:]] = pid
+                        load[pid] += seg.size - off
+                        off = seg.size
+                    if home is None:
+                        self.flow_table[f] = pid   # first pipeline: "home"
+                        home = pid
+                    elif pid != home:
+                        sp = self.spill_table.setdefault(f, [])
+                        if pid not in sp:
+                            sp.append(pid)
+                if (was_new and self.trace is not None
+                        and traced < PLACE_TRACE_CAP):
+                    traced += 1
+                    self.trace.event("slow_path_place", tenant=tenant,
+                                     flow=f, pipeline=int(home),
+                                     reason="new_flow")
 
         for p, l in zip(self.pipelines, load):
             p.load = float(l)
@@ -337,138 +350,142 @@ class TrafficOrchestrator:
         # The replica loop runs on native Python scalars (identical float64
         # arithmetic, ~3x less per-miss overhead than 8-wide numpy temps).
         # Python max() and np.argmax agree on ties: both keep the first max.
-        cap_l = cap.tolist()
-        active_l = active.tolist()
-        hp_l = hit_prefix.tolist()
-        taken_l = [0.0] * npipe
-        over_l = [0.0] * npipe
-        pend_home: Dict[int, int] = {}
-        pend_spill: Dict[int, List[int]] = {}
-        miss_homes = np.empty(M, np.int64)
-        miss_clean = np.zeros(M, bool)         # cacheable: single-pipeline
-        places: List = []                      # sampled trace tuples
-        mfids = uniq[morder].tolist()
-        mrank_l = mrank.tolist()
-        ft_get = self.flow_table.get
-        sp_get = self.spill_table.get
-        pipe_rng = range(npipe)
-        want_trace = self.trace is not None
+        with span("meili.to.miss_loop"):
+            cap_l = cap.tolist()
+            active_l = active.tolist()
+            hp_l = hit_prefix.tolist()
+            taken_l = [0.0] * npipe
+            over_l = [0.0] * npipe
+            pend_home: Dict[int, int] = {}
+            pend_spill: Dict[int, List[int]] = {}
+            miss_homes = np.empty(M, np.int64)
+            miss_clean = np.zeros(M, bool)         # cacheable: single-pipeline
+            places: List = []                      # sampled trace tuples
+            mfids = uniq[morder].tolist()
+            mrank_l = mrank.tolist()
+            ft_get = self.flow_table.get
+            sp_get = self.spill_table.get
+            pipe_rng = range(npipe)
+            want_trace = self.trace is not None
 
-        for k in range(M):
-            f = mfids[k]
-            r = mrank_l[k]
-            seg = mseq[mstart[r]:mstart[r + 1]]
-            nseg = seg.size
-            hpk = hp_l[k]
-            avail = [cap_l[i] - hpk[i] - taken_l[i] if active_l[i] else
-                     -hpk[i] - taken_l[i] for i in pipe_rng]
-            home = ft_get(f)
-            was_new = home is None
-            off = 0
-            clean = True
+            for k in range(M):
+                f = mfids[k]
+                r = mrank_l[k]
+                seg = mseq[mstart[r]:mstart[r + 1]]
+                nseg = seg.size
+                hpk = hp_l[k]
+                avail = [cap_l[i] - hpk[i] - taken_l[i] if active_l[i] else
+                         -hpk[i] - taken_l[i] for i in pipe_rng]
+                home = ft_get(f)
+                was_new = home is None
+                off = 0
+                clean = True
 
-            def grab(pid: int, off: int) -> int:
-                a = avail[pid]
-                if a < 1.0:
-                    return off
-                take = min(nseg - off, int(a))
-                assign[seg[off:off + take]] = pid
-                taken_l[pid] += take
-                avail[pid] = a - take
-                return off + take
+                def grab(pid: int, off: int) -> int:
+                    a = avail[pid]
+                    if a < 1.0:
+                        return off
+                    take = min(nseg - off, int(a))
+                    assign[seg[off:off + take]] = pid
+                    taken_l[pid] += take
+                    avail[pid] = a - take
+                    return off + take
 
-            if home is not None and active_l[home]:
-                off = grab(home, off)
-            if off < nseg:
-                for spid in sp_get(f, ()):
-                    if active_l[spid]:
-                        noff = grab(spid, off)
-                        if noff != off:
-                            clean = False
-                            off = noff
-                    if off == nseg:
-                        break
-            while off < nseg:
-                pid = max(pipe_rng,
-                          key=lambda i: avail[i] if active_l[i] else -1.0)
-                if avail[pid] >= 1.0:
-                    off = grab(pid, off)
-                else:
+                if home is not None and active_l[home]:
+                    off = grab(home, off)
+                if off < nseg:
+                    for spid in sp_get(f, ()):
+                        if active_l[spid]:
+                            noff = grab(spid, off)
+                            if noff != off:
+                                clean = False
+                                off = noff
+                        if off == nseg:
+                            break
+                while off < nseg:
                     pid = max(pipe_rng,
-                              key=lambda i: cap_l[i] if active_l[i] else -1.0)
-                    assign[seg[off:]] = pid
-                    over_l[pid] += nseg - off
-                    off = nseg
-                if home is None:
-                    pend_home[f] = pid
-                    home = pid
-                elif pid != home:
-                    clean = False
-                    sp = pend_spill.get(f)
-                    if sp is None:
-                        sp = pend_spill[f] = list(sp_get(f, ()))
-                    if pid not in sp:
-                        sp.append(pid)
-            miss_homes[k] = home
-            # Cache only flows served entirely by one pipeline (their home):
-            # a heavy spiller must NOT become a hit — charging it all to home
-            # would force a fallback every batch. Left uncached it stays a
-            # miss and the replica loop spills it exactly like the slow path.
-            # ``clean`` tracked inline == (assign[seg] == home).all(): every
-            # packet lands via grab(home)/first-grab-of-a-new-flow unless a
-            # spill/argmax/overload branch assigned some other pipeline.
-            miss_clean[k] = clean
-            if want_trace and len(places) < PLACE_TRACE_CAP:
-                u = morder[k]
-                if slot[u] < 0:
-                    reason = "new_flow" if was_new else "cache_evicted"
-                elif not fresh[u]:
-                    reason = "stale_epoch"
-                else:
-                    reason = "inactive_home"
-                places.append((f, int(home), reason))
+                              key=lambda i: avail[i] if active_l[i] else -1.0)
+                    if avail[pid] >= 1.0:
+                        off = grab(pid, off)
+                    else:
+                        pid = max(pipe_rng,
+                                  key=lambda i: (cap_l[i] if active_l[i]
+                                                 else -1.0))
+                        assign[seg[off:]] = pid
+                        over_l[pid] += nseg - off
+                        off = nseg
+                    if home is None:
+                        pend_home[f] = pid
+                        home = pid
+                    elif pid != home:
+                        clean = False
+                        sp = pend_spill.get(f)
+                        if sp is None:
+                            sp = pend_spill[f] = list(sp_get(f, ()))
+                        if pid not in sp:
+                            sp.append(pid)
+                miss_homes[k] = home
+                # Cache only flows served entirely by one pipeline (their
+                # home): a heavy spiller must NOT become a hit — charging it
+                # all to home would force a fallback every batch. Left
+                # uncached it stays a miss and the replica loop spills it
+                # exactly like the slow path. ``clean`` tracked inline ==
+                # (assign[seg] == home).all(): every packet lands via
+                # grab(home)/first-grab-of-a-new-flow unless a
+                # spill/argmax/overload branch assigned some other pipeline.
+                miss_clean[k] = clean
+                if want_trace and len(places) < PLACE_TRACE_CAP:
+                    u = morder[k]
+                    if slot[u] < 0:
+                        reason = "new_flow" if was_new else "cache_evicted"
+                    elif not fresh[u]:
+                        reason = "stale_epoch"
+                    else:
+                        reason = "inactive_home"
+                    places.append((f, int(home), reason))
 
-        taken = np.array(taken_l, np.float64)
-        over = np.array(over_l, np.float64)
-        ok = bool(np.all(hit_charge + taken <= cap))
-        if not ok:
-            # Some hit would have spilled at its turn: the cached answer is
-            # not the slow-path answer. Discard everything.
-            assign[:] = ASSIGN_NONE
-            self.fast_stats["fallbacks"] += 1
-            cache.stats["fallbacks"] += 1
+        with span("meili.to.commit"):
+            taken = np.array(taken_l, np.float64)
+            over = np.array(over_l, np.float64)
+            ok = bool(np.all(hit_charge + taken <= cap))
+            if not ok:
+                # Some hit would have spilled at its turn: the cached answer is
+                # not the slow-path answer. Discard everything.
+                assign[:] = ASSIGN_NONE
+                self.fast_stats["fallbacks"] += 1
+                cache.stats["fallbacks"] += 1
+                if self.trace is not None:
+                    self.trace.event("fast_path_fallback", tenant=tenant,
+                                     flows=int(F), hits=int(hsel.size),
+                                     reason="hit_overcommit")
+                return False
+
+            self.flow_table.update(pend_home)
+            for f, sp in pend_spill.items():
+                self.spill_table[f] = sp
+            load = hit_charge + taken + over
+            for p, l in zip(self.pipelines, load):
+                p.load = float(l)
+
+            cache.touch(slot[hsel], self._round)
+            if miss_clean.any():
+                cache.record(uniq[morder[miss_clean]], miss_homes[miss_clean],
+                             self._round)
+            cache.stats["hits"] += int(hsel.size)
+            cache.stats["misses"] += int(M)
+            fs = self.fast_stats
+            fs["fast_batches"] += 1
+            fs["hit_flows"] += int(hsel.size)
+            fs["miss_flows"] += int(M)
+            fs["hit_pkts"] += int(counts[hsel].sum())
+            fs["miss_pkts"] += int(counts[morder].sum())
             if self.trace is not None:
-                self.trace.event("fast_path_fallback", tenant=tenant,
+                for f, pid, reason in places:
+                    self.trace.event("slow_path_place", tenant=tenant, flow=f,
+                                     pipeline=pid, reason=reason)
+                self.trace.event("flow_cache_batch", tenant=tenant,
                                  flows=int(F), hits=int(hsel.size),
-                                 reason="hit_overcommit")
-            return False
-
-        self.flow_table.update(pend_home)
-        for f, sp in pend_spill.items():
-            self.spill_table[f] = sp
-        load = hit_charge + taken + over
-        for p, l in zip(self.pipelines, load):
-            p.load = float(l)
-
-        cache.touch(slot[hsel], self._round)
-        if miss_clean.any():
-            cache.record(uniq[morder[miss_clean]], miss_homes[miss_clean],
-                         self._round)
-        cache.stats["hits"] += int(hsel.size)
-        cache.stats["misses"] += int(M)
-        fs = self.fast_stats
-        fs["fast_batches"] += 1
-        fs["hit_flows"] += int(hsel.size)
-        fs["miss_flows"] += int(M)
-        fs["hit_pkts"] += int(counts[hsel].sum())
-        fs["miss_pkts"] += int(counts[morder].sum())
-        if self.trace is not None:
-            for f, pid, reason in places:
-                self.trace.event("slow_path_place", tenant=tenant, flow=f,
-                                 pipeline=pid, reason=reason)
-            self.trace.event("flow_cache_batch", tenant=tenant,
-                             flows=int(F), hits=int(hsel.size),
-                             misses=int(M), halted=int(halted.sum()))
+                                 misses=int(M), halted=int(halted.sum()))
         return True
 
     def _record_slow(self, assign: np.ndarray, uniq: np.ndarray,

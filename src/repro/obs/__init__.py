@@ -8,6 +8,9 @@ runtime reuses the controller's so all layers write one log. Recording is
 always on — events are list appends and histogram observes, cheap enough
 that the chaos benchmark's wall-clock budget (<5% overhead) holds — and
 export is explicit (``dump``).
+
+``spans`` is apart from ``Obs``: the data plane's per-batch phase spans,
+written into the profiler's trace and into one process-wide record.
 """
 from __future__ import annotations
 
@@ -17,6 +20,7 @@ from typing import Iterable, Optional
 from repro.obs.metrics import (Counter, Gauge, Histogram,  # noqa: F401
                                MetricsRegistry)
 from repro.obs.percentiles import P2Quantile, Reservoir    # noqa: F401
+from repro.obs import spans                               # noqa: F401
 from repro.obs.trace import (DECISION, FAULT, MARK, RECONCILE,  # noqa: F401
                              SPAN, DecisionTrace, Span, TraceEvent)
 

@@ -89,20 +89,23 @@ def test_no_recompiles_after_warmup_via_cache_counters():
 
 
 def test_dataplane_metrics_and_stage_profile():
-    """With a metrics registry attached, dispatch calls/compiles and (in
-    profile mode) per-stage device timings land as labeled series."""
-    from repro.obs import Obs
+    """With a metrics registry attached, dispatch calls/compiles and
+    per-stage device timings land as labeled series; each dispatch is one
+    ``meili.dispatch.index`` span and one ``meili.dispatch.enqueue`` span."""
+    from repro.obs import Obs, spans
 
     obs = Obs()
     app = ALL_APPS(impl="ref")["FW"]
     dp = ParallelDataPlane(app, num_pipelines=2, capacity_per_pipeline=32,
-                           metrics=obs.metrics, profile=True)
+                           metrics=obs.metrics)
+    before = {n: spans.totals().get(n, {"calls": 0})["calls"]
+              for n in ("meili.dispatch.index", "meili.dispatch.enqueue")}
     for _ in range(3):
         dp.process(PKTS)
     calls = obs.metrics.get("dataplane_dispatch_calls_total", app=app.name)
     assert calls is not None and calls.value == 3
-    lat = obs.metrics.get("dataplane_dispatch_us", app=app.name)
-    assert lat is not None and lat.count == 3 and lat.quantile(0.5) > 0
+    assert {n: spans.totals()[n]["calls"] - c
+            for n, c in before.items()} == dict.fromkeys(before, 3)
     timings = dp.profile_stages(PKTS)
     assert set(timings) == set(app.stage_names())
     for s in app.stage_names():
