@@ -6,8 +6,8 @@ flow-id window), batched at 16k packets over 8 pipelines with 2× capacity
 headroom. Two arms process the SAME tick sequence:
 
   cache arm — ParallelDataPlane with the flow cache (default config,
-              2^18-slot table): steady-state classification is one device
-              lookup + an O(misses) slow loop;
+              2^18-slot table): steady-state classification is one host
+              table probe + an O(misses) slow loop;
   slow arm  — flow_cache=False: the full per-unique-flow Python loop every
               batch (the pre-ISSUE-9 data plane).
 
@@ -18,10 +18,10 @@ so dilutes any end-to-end ratio), ``speedup`` (classification, the ≥5×
 bar), ``speedup_e2e`` (whole process() call), steady-state hit rate
 (flow-level and packet-weighted — the committed bar gates the
 packet-weighted one), eviction/invalidation/fallback counters, and
-steady-state recompiles (fused dispatch + lookup/scatter kernels, via
-trace-time counters) which must be zero — the cache is prewarmed across
-every pow-2 bucket before the timed window. Arms are interleaved over the
-same tick chunks and each takes its min-over-rounds (contention-robust).
+steady-state recompiles (fused dispatch + flow-lookup kernels, via
+trace-time counters) which must be zero — both arms are warmed before the
+timed window. Arms are interleaved over the same tick chunks and each
+takes its min-over-rounds (contention-robust).
 
 Results merge into BENCH_dataplane.json under the ``megaflow`` key
 (bench_dataplane preserves it when rewriting its grid) and are gated by

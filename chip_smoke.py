@@ -123,13 +123,11 @@ def phase_a(seed: int, batch: int = BATCH, flows: int = FLOWS,
         if recompiles:
             raise AssertionError(f"{name}: {recompiles} dispatch compiles "
                                  f"after batch 1")
-        fc = dp.to.flow_cache
         texts[name] = last.text()
         print(f"phase A {name:4s} batches 2-{batches}: {sum(secs[1:]):.6f} s "
               f"(batch 1 with compile: {secs[0]:.3f} s)  dispatch compiles "
               f"after batch 1: {recompiles}  flow-cache hits: "
-              f"{fc.stats['hits']}  flow-cache backend: {fc.backend}  "
-              f"== ref oracle", flush=True)
+              f"{dp.to.flow_cache.stats['hits']}  == ref oracle", flush=True)
     return texts
 
 

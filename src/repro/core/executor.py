@@ -142,7 +142,7 @@ class ParallelDataPlane:
         self.app = app
         self.R = R
         # Megaflow fast path (ISSUE 9): classification served from the
-        # device-resident exact-match cache; the TO's slow loop runs only on
+        # host-resident exact-match cache; the TO's slow loop runs only on
         # misses. `flow_cache=False` restores the pure slow path (the bench
         # baseline arm); semantics are byte-identical either way.
         fc = None

@@ -1,4 +1,4 @@
-"""Device-resident exact-match flow cache — the megaflow fast path (ISSUE 9).
+"""Host-resident exact-match flow cache — the megaflow fast path.
 
 The OVS hardware-offload split, reproduced for the Traffic Orchestrator: the
 FIRST packet batch of a flow takes the slow path (the full §5.1.2 placement
@@ -7,11 +7,11 @@ hits this exact-match table, so steady-state per-batch control cost is
 O(cache misses), not O(unique flows).
 
 Structure: an open-addressed fid -> (pipeline, epoch) table with bounded
-probe windows (``kernels.flow_lookup`` holds the probe math and both
-lookup implementations). The table is mirrored as device arrays: batch
-lookups run as one jitted XLA gather program, and host-side mutations
-— inserts, refreshes, deletions — are streamed to the device as bucketed
-scatter updates, so a pure-hit steady state moves nothing host->device.
+probe windows (``kernels.flow_lookup`` holds the probe math). The table
+lives on the host, where the orchestrator runs: batch lookups and every
+mutation probe it with the vectorized numpy probe ``lookup_numpy``. Nothing
+on the lookup path enqueues device work or waits for it, so classifying
+batch k+1 never queues behind batch k's dispatch on the device.
 
 Consistency is by *epoch*, not by scanning: any control-plane action that
 can re-home flows (migration begin/finish, pipeline halt/add, failover)
@@ -37,7 +37,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import flow_lookup as fl
@@ -54,7 +53,6 @@ class FlowCacheConfig:
     window: int = 8                # bounded probe window (slots per key)
     idle_ttl: int = 4096           # rounds before an untouched entry expires
     expire_every: int = 256        # rounds between idle-expiry sweeps
-    backend: str = "jnp"           # jnp (device probe) | numpy (host oracle)
     seed: int = 0                  # clock-eviction tie-break seed
     enabled: bool = True           # False: recency ledger only, no fast path
 
@@ -68,7 +66,6 @@ class FlowCache:
         self.capacity = cap
         self.window = int(self.cfg.window)
         assert self.window <= cap
-        self.backend = self.cfg.backend
         self.epoch = 0
         # Host-authoritative planes. pid < 0 == empty slot.
         self.key_lo = np.zeros(cap, np.uint32)
@@ -79,17 +76,10 @@ class FlowCache:
         self.ref = np.zeros(cap, np.uint8)       # second-chance bit
         # Seeded tie-break for clock eviction among equal stamps.
         self._tie = np.random.default_rng(self.cfg.seed).random(cap)
-        # Device mirror of the lookup planes (key_lo/key_hi/pid/ep). Host
-        # mutations accumulate in _pending (slot indices) and are flushed as
-        # one bucketed scatter before the next device lookup; stamps/refs
-        # never leave the host (the kernel does not read them).
-        self._planes: Optional[Tuple] = None
-        self._pending: list = []
-        self._full_upload = True
         self.stats: Dict[str, int] = {
             "hits": 0, "misses": 0, "evictions": 0, "invalidations": 0,
             "expirations": 0, "inserts": 0, "refreshes": 0, "fallbacks": 0,
-            "lookups": 0, "uploads": 0, "scatter_updates": 0,
+            "lookups": 0,
         }
 
     # -- epoch ----------------------------------------------------------------
@@ -108,53 +98,19 @@ class FlowCache:
         fids = np.asarray(fids, np.int64)
         self.stats["lookups"] += int(fids.size)
         lo, hi = fl.split_fids(fids)
-        if self.backend == "numpy" or fids.size == 0:
-            return fl.lookup_numpy(self.key_lo, self.key_hi, self.pid,
-                                   self.ep, lo, hi, self.epoch, self.window)
+        if fids.size == 0:
+            return self._probe(lo, hi)
         with span("meili.to.probe"):
-            planes = self._device_planes()
-            F = fids.size
-            Fp = _pow2(F)
-            if Fp != F:
-                lo = np.concatenate([lo, np.zeros(Fp - F, np.uint32)])
-                hi = np.concatenate([hi, np.zeros(Fp - F, np.uint32)])
-            slot, pid, fresh = fl.lookup_jnp(*planes, jnp.asarray(lo),
-                                             jnp.asarray(hi), self.epoch,
-                                             window=self.window)
-            return (np.asarray(slot)[:F].astype(np.int64),
-                    np.asarray(pid)[:F], np.asarray(fresh)[:F])
+            return self._probe(lo, hi)
 
-    def _device_planes(self) -> Tuple:
-        if self._planes is None or self._full_upload:
-            self._planes = (jnp.asarray(self.key_lo), jnp.asarray(self.key_hi),
-                            jnp.asarray(self.pid), jnp.asarray(self.ep))
-            self._full_upload = False
-            self._pending.clear()
-            self.stats["uploads"] += 1
-        elif self._pending:
-            slots = np.unique(np.concatenate(self._pending))
-            n = slots.size
-            npad = _pow2(n)
-            pad = np.full(npad - n, self.capacity, np.int64)  # dropped
-            s = np.concatenate([slots, pad])
-            safe = np.concatenate([slots, np.zeros(npad - n, np.int64)])
-            self._planes = fl.apply_updates(
-                self._planes, s, self.key_lo[safe], self.key_hi[safe],
-                self.pid[safe], self.ep[safe])
-            self._pending.clear()
-            self.stats["scatter_updates"] += 1
-        return self._planes
-
-    def _mark(self, slots: np.ndarray) -> None:
-        if slots.size:
-            if len(self._pending) > 64:          # coalesce long mutation runs
-                self._pending = [np.unique(np.concatenate(self._pending))]
-            self._pending.append(np.asarray(slots, np.int64))
+    def _probe(self, lo: np.ndarray, hi: np.ndarray
+               ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        return fl.lookup_numpy(self.key_lo, self.key_hi, self.pid, self.ep,
+                               lo, hi, self.epoch, self.window)
 
     # -- mutation --------------------------------------------------------------
     def touch(self, slots: np.ndarray, round_: int) -> None:
-        """LRU touch on assignment: hits refresh recency + reference bit.
-        Host-only state — no device traffic in a pure-hit steady state."""
+        """LRU touch on assignment: hits refresh recency + reference bit."""
         slots = np.asarray(slots, np.int64)
         slots = slots[slots >= 0]
         if slots.size:
@@ -174,7 +130,6 @@ class FlowCache:
         self.stamp[slots] = round_
         self.ref[slots] = 1
         self.stats["refreshes"] += int(slots.size)
-        self._mark(slots)
 
     def insert(self, fids: np.ndarray, pids: np.ndarray, round_: int) -> None:
         """Insert new keys (callers pass keys ``lookup`` reported absent).
@@ -207,7 +162,6 @@ class FlowCache:
         self.stamp[tgt] = round_
         self.ref[tgt] = 1
         self.stats["inserts"] += int(tgt.size)
-        self._mark(tgt)
         for i in np.nonzero(~ok)[0]:
             self._insert_one(int(win[i][0]), win[i], lo[i], hi[i],
                              int(pids[i]), round_)
@@ -236,7 +190,6 @@ class FlowCache:
         self.stamp[slot] = round_
         self.ref[slot] = 1
         self.stats["inserts"] += 1
-        self._mark(np.array([slot], np.int64))
 
     def record(self, fids: np.ndarray, pids: np.ndarray, round_: int) -> None:
         """Post-slow-path bookkeeping: touch/refresh present keys, insert
@@ -246,9 +199,7 @@ class FlowCache:
             return
         pids = np.asarray(pids, np.int32)
         lo, hi = fl.split_fids(fids)
-        slot, _, fresh = fl.lookup_numpy(self.key_lo, self.key_hi, self.pid,
-                                         self.ep, lo, hi, self.epoch,
-                                         self.window)
+        slot, _, fresh = self._probe(lo, hi)
         present = slot >= 0
         stale = present & ~fresh
         self.touch(slot[present], round_)
@@ -270,12 +221,10 @@ class FlowCache:
         if not fids.size:
             return 0
         lo, hi = fl.split_fids(fids)
-        slot, _, _ = fl.lookup_numpy(self.key_lo, self.key_hi, self.pid,
-                                     self.ep, lo, hi, self.epoch, self.window)
+        slot, _, _ = self._probe(lo, hi)
         slots = slot[slot >= 0]
         if slots.size:
             self.pid[slots] = -1
-            self._mark(slots)
         return int(slots.size)
 
     def expire_idle(self, round_: int) -> int:
@@ -286,30 +235,13 @@ class FlowCache:
         if old.size:
             self.pid[old] = -1
             self.stats["expirations"] += int(old.size)
-            self._mark(old)
         return int(old.size)
 
     def prewarm(self, max_queries: int = 1 << 14,
                 max_updates: int = 1 << 12) -> None:
-        """Compile every pow-2 specialization the steady state can touch
-        (query buckets up to ``max_queries``, scatter buckets up to
-        ``max_updates``) so benchmark windows observe zero recompiles."""
-        if self.backend == "numpy":
-            return
-        planes = self._device_planes()
-        n = 16
-        while n <= max_queries:
-            self.lookup(np.zeros(n, np.int64))
-            n <<= 1
-        n = 16
-        while n <= min(max_updates, self.capacity):
-            # All-sentinel slots: dropped by the scatter, planes unchanged.
-            s = np.full(n, self.capacity, np.int64)
-            z = np.zeros(n, np.uint32)
-            zi = np.zeros(n, np.int32)
-            self._planes = fl.apply_updates(planes, s, z, z, zi, zi)
-            planes = self._planes
-            n <<= 1
+        """Nothing to compile: the probe runs on the host, so this returns
+        at once. Kept for callers that warm every shape before a timed
+        window."""
 
     # -- introspection ---------------------------------------------------------
     def last_seen(self, fids: np.ndarray) -> np.ndarray:
@@ -318,8 +250,7 @@ class FlowCache:
         if not fids.size:
             return np.zeros(0, np.int64)
         lo, hi = fl.split_fids(fids)
-        slot, _, _ = fl.lookup_numpy(self.key_lo, self.key_hi, self.pid,
-                                     self.ep, lo, hi, self.epoch, self.window)
+        slot, _, _ = self._probe(lo, hi)
         return np.where(slot >= 0, self.stamp[np.where(slot >= 0, slot, 0)],
                         -1).astype(np.int64)
 
@@ -328,13 +259,3 @@ class FlowCache:
 
     def stats_snapshot(self) -> Dict[str, int]:
         return dict(self.stats, occupancy=self.occupancy(), epoch=self.epoch)
-
-    def check_device_mirror(self) -> bool:
-        """Test hook: the device planes must equal the host planes after a
-        flush (incremental scatters may not drift)."""
-        if self.backend == "numpy" or self._planes is None:
-            return True
-        planes = self._device_planes()
-        host = (self.key_lo, self.key_hi, self.pid, self.ep)
-        return all(np.array_equal(np.asarray(d), h)
-                   for d, h in zip(planes, host))

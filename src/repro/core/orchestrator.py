@@ -121,7 +121,7 @@ class TrafficOrchestrator:
         once per *flow*; per-packet work is numpy scatter only.
 
         With a ``flow_cache`` attached, flows with a fresh cache entry skip
-        the decision loop entirely (megaflow fast path): one device lookup
+        the decision loop entirely (megaflow fast path): one host table probe
         classifies the batch and only cache *misses* run the slow loop below.
         The fast path is byte-identical to the slow path — it validates that
         every cache hit would have been served fully by its home pipeline at

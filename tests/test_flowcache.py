@@ -17,16 +17,17 @@ from hypothesis import given, settings, strategies as st
 from repro.apps.packets import pareto_flow_weights, synth_packets_weighted
 from repro.core.flowcache import FlowCache, FlowCacheConfig
 from repro.core.orchestrator import TrafficOrchestrator
+from repro.kernels import flow_lookup as fl
+from repro.obs import spans
 from repro.obs.trace import DecisionTrace
 
 NPIPE = 4
 
 
-def _pair(cap, *, capacity=1 << 10, backend="numpy", table_cap=None,
-          trace=None, idle_ttl=4096, expire_every=256):
+def _pair(cap, *, capacity=1 << 10, table_cap=None, trace=None,
+          idle_ttl=4096, expire_every=256):
     """(cache-on, cache-off) orchestrators with identical topology."""
-    fc = FlowCache(FlowCacheConfig(capacity=capacity, backend=backend,
-                                   idle_ttl=idle_ttl,
+    fc = FlowCache(FlowCacheConfig(capacity=capacity, idle_ttl=idle_ttl,
                                    expire_every=expire_every))
     a = TrafficOrchestrator(num_pipelines=NPIPE, capacity_per_pipeline=cap,
                             flow_cache=fc, table_cap=table_cap, trace=trace)
@@ -139,8 +140,8 @@ def test_halted_flow_buffering_identical():
 # -- state bounding (satellite a) ---------------------------------------------
 
 def test_flow_table_bounded_under_churn():
-    fc = FlowCache(FlowCacheConfig(capacity=1 << 8, backend="numpy",
-                                   idle_ttl=16, expire_every=8))
+    fc = FlowCache(FlowCacheConfig(capacity=1 << 8, idle_ttl=16,
+                                   expire_every=8))
     to = TrafficOrchestrator(num_pipelines=NPIPE, capacity_per_pipeline=256.0,
                             flow_cache=fc, table_cap=200)
     for t in range(60):
@@ -153,8 +154,8 @@ def test_flow_table_bounded_under_churn():
 def test_idle_expiry_clears_departed_flows():
     # No table_cap: idle expiry alone (not pruning) must clear entries for
     # flows that churned out of the window.
-    fc = FlowCache(FlowCacheConfig(capacity=1 << 9, backend="numpy",
-                                   idle_ttl=8, expire_every=4))
+    fc = FlowCache(FlowCacheConfig(capacity=1 << 9, idle_ttl=8,
+                                   expire_every=4))
     to = TrafficOrchestrator(num_pipelines=NPIPE, capacity_per_pipeline=256.0,
                             flow_cache=fc)
     for t in range(40):
@@ -164,8 +165,8 @@ def test_idle_expiry_clears_departed_flows():
 
 
 def test_expired_flow_returning_replaces_correctly():
-    fc = FlowCache(FlowCacheConfig(capacity=1 << 8, backend="numpy",
-                                   idle_ttl=4, expire_every=2))
+    fc = FlowCache(FlowCacheConfig(capacity=1 << 8, idle_ttl=4,
+                                   expire_every=2))
     to = TrafficOrchestrator(num_pipelines=NPIPE, capacity_per_pipeline=256.0,
                             flow_cache=fc, table_cap=64)
     ref = TrafficOrchestrator(num_pipelines=NPIPE,
@@ -191,7 +192,7 @@ def test_expired_flow_returning_replaces_correctly():
 
 def test_trace_explains_placements_and_cache_batches():
     trace = DecisionTrace()
-    fc = FlowCache(FlowCacheConfig(capacity=1 << 9, backend="numpy"))
+    fc = FlowCache(FlowCacheConfig(capacity=1 << 9))
     to = TrafficOrchestrator(num_pipelines=NPIPE, capacity_per_pipeline=256.0,
                             flow_cache=fc, trace=trace)
     for t in range(3):
@@ -220,18 +221,52 @@ def test_invalidation_reasons_counted():
     assert fc.stats["invalidations"] == 3
 
 
-def test_device_mirror_consistent_after_mutations():
-    fc = FlowCache(FlowCacheConfig(capacity=1 << 8, backend="jnp"))
+def _host_lookup(fc, fids):
+    lo, hi = fl.split_fids(fids)
+    return fl.lookup_numpy(fc.key_lo, fc.key_hi, fc.pid, fc.ep, lo, hi,
+                           fc.epoch, fc.window)
+
+
+def test_lookup_probes_host_table_without_device_work():
+    fc = FlowCache(FlowCacheConfig(capacity=1 << 8))
     rng = np.random.default_rng(0)
     fids = rng.choice(1 << 40, size=150, replace=False).astype(np.int64)
+    traces = fl.trace_counts()
+
+    def check(round_):
+        got = fc.lookup(fids)
+        for g, w in zip(got, _host_lookup(fc, fids)):
+            np.testing.assert_array_equal(g, w, err_msg=f"round {round_}")
+        return got
+
     fc.record(fids, rng.integers(0, NPIPE, 150).astype(np.int64), 1)
-    fc.lookup(fids)                    # flush pending scatters
-    assert fc.check_device_mirror()
+    slot, _, fresh = check(1)
+    assert (slot >= 0).any() and fresh.any()
     fc.delete(fids[:50])
+    assert (check(2)[0][:50] == -1).all()
     fc.invalidate("test")
-    fc.record(fids[50:100], np.ones(50, np.int64), 2)
+    assert not check(3)[2].any()               # every entry stale
+    fc.record(fids[50:100], np.ones(50, np.int64), 4)
+    fc.insert(fids[:10], np.zeros(10, np.int64), 4)
+    check(4)
+    assert fc.expire_idle(4 + fc.cfg.idle_ttl + 1) > 0
+    assert (check(5)[0] == -1).all()           # everything expired
+    assert fl.trace_counts() == traces
+
+
+def test_lookup_is_one_probe_span_per_nonempty_batch():
+    fc = FlowCache(FlowCacheConfig(capacity=1 << 8))
+    fids = np.arange(1, 41, dtype=np.int64) << 33
+    fc.record(fids, np.arange(40) % NPIPE, 1)
+
+    def probes():
+        return spans.totals().get("meili.to.probe", {"calls": 0})["calls"]
+
+    n = probes()
     fc.lookup(fids)
-    assert fc.check_device_mirror()
+    assert probes() == n + 1
+    fc.lookup(np.zeros(0, np.int64))
+    assert probes() == n + 1
 
 
 # -- benchmark smoke (satellite e) --------------------------------------------
