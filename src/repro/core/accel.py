@@ -21,6 +21,7 @@ import numpy as np
 from repro.core import pool
 from repro.core.graph import Function, PacketBatch
 from repro.kernels import ops
+from repro.obs import spans
 
 
 def _payload_words(batch: PacketBatch) -> jnp.ndarray:
@@ -41,14 +42,19 @@ def _words_to_payload(words: jnp.ndarray, orig: jnp.ndarray) -> jnp.ndarray:
 
 def regex(rules: Sequence[str], *, impl: Optional[str] = None,
           name: str = "regex") -> Function:
-    """Multi-pattern matching; match count lands in meta['match_num']."""
-    table, out_count = ops.build_aho_corasick(rules)
-    table_j, out_j = jnp.asarray(table), jnp.asarray(out_count)
+    """Multi-pattern matching; match count lands in meta['match_num'].
+
+    The rules are compiled once, here, inside the ``meili.regex.compile``
+    span; the gauges ``meili.regex.states`` and ``meili.regex.device_bytes``
+    then hold the automaton's states and what the compiled rules keep on the
+    device."""
+    with spans.span("meili.regex.compile", rules=len(rules)):
+        match, states, device_bytes = ops.regex_matcher(rules, impl=impl)
+    spans.set_gauge("meili.regex.states", states)
+    spans.set_gauge("meili.regex.device_bytes", device_bytes)
 
     def ucf(batch: PacketBatch) -> PacketBatch:
-        matches = ops.regex_scan(batch.payload, batch.length, table_j, out_j,
-                                 impl=impl)
-        return batch.with_meta(match_num=matches)
+        return batch.with_meta(match_num=match(batch.payload, batch.length))
 
     return Function(name, "accel", ucf, resource=pool.REGEX,
                     params={"rules": list(rules)})

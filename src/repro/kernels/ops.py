@@ -26,10 +26,8 @@ from repro.kernels import ref as _ref
 from repro.kernels import flash_attention as _fa
 from repro.kernels import decode_attention as _da
 from repro.kernels import ssd_scan as _ssd
-from repro.kernels import dfa_regex as _dfa
+from repro.kernels import dfa_scan as _scan
 from repro.kernels import crypto as _crypto
-
-build_aho_corasick = _ref.build_aho_corasick
 
 
 def default_impl() -> str:
@@ -258,15 +256,32 @@ def ssd(x, a, b, c, *, chunk: int = 128, impl: Optional[str] = None):
 # NIC accelerator ops (regex / crypto / hash).
 # ---------------------------------------------------------------------------
 
-def regex_scan(payload, length, table, out_count, *, impl: Optional[str] = None,
-               block_b: int = 128):
+def regex_matcher(rules, *, impl: Optional[str] = None):
+    """Compile literal rules for the regex accelerator. Returns ``(match,
+    states, device_bytes)``: ``match(payload, length)`` gives per-packet
+    occurrence counts, ``states`` is the size of the rules' automaton, and
+    ``device_bytes`` is what the compiled rules hold on the device. The
+    device path is the filter-and-verify scan (``kernels/dfa_scan.py``),
+    whose work per byte and VMEM blocks do not grow with the states; ``ref``
+    and ``blocked`` step the dense automaton (``ref.dfa_scan``)."""
     impl = impl or default_impl()
+    states = int(_ref.trie(rules)[0].size)
     if impl in ("ref", "blocked"):
-        return _ref.dfa_scan(payload, length, jnp.asarray(table),
-                             jnp.asarray(out_count))
-    return _dfa.dfa_regex(payload, length, jnp.asarray(table),
-                          jnp.asarray(out_count), block_b=block_b,
+        table, out_count = _ref.build_aho_corasick(rules)
+        table_j, out_j = jnp.asarray(table), jnp.asarray(out_count)
+
+        def match(payload, length):
+            return _ref.dfa_scan(payload, length, table_j, out_j)
+
+        return match, states, int(table.nbytes + out_count.nbytes)
+    lit = _scan.compile_literals(rules)
+    bloom, buckets = jnp.asarray(lit.bloom), jnp.asarray(lit.buckets)
+
+    def match(payload, length):
+        return _scan.scan(payload, length, lit, bloom, buckets,
                           interpret=(impl == "interpret"))
+
+    return match, states, lit.nbytes
 
 
 def cipher(words, key, *, impl: Optional[str] = None, block_b: int = 256):

@@ -15,50 +15,58 @@ import numpy as np
 # Multi-pattern DFA (Aho-Corasick) — the paper's regex accelerator.
 # ---------------------------------------------------------------------------
 
+def trie(patterns) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """The trie of literal byte patterns, states in insertion order (0 is
+    the root): each state's parent, the byte on its edge, its depth, and
+    how many patterns end at it."""
+    patterns = [p.encode() if isinstance(p, str) else bytes(p) for p in patterns]
+    goto = [{}]
+    out = [0]
+    parent, edge, depth = [0], [0], [0]
+    for pat in patterns:
+        s = 0
+        for ch in pat:
+            nxt = goto[s].get(ch)
+            if nxt is None:
+                nxt = goto[s][ch] = len(goto)
+                goto.append({})
+                out.append(0)
+                parent.append(s)
+                edge.append(ch)
+                depth.append(depth[s] + 1)
+            s = nxt
+        out[s] += 1
+    return (np.asarray(parent, np.int64), np.asarray(edge, np.int64),
+            np.asarray(depth, np.int64), np.asarray(out, np.int64))
+
+
 def build_aho_corasick(patterns) -> tuple[np.ndarray, np.ndarray]:
     """Compile literal byte patterns into a dense DFA.
 
     Returns (table, out_count): table[s, b] = next state, out_count[s] = number
     of pattern occurrences ending when entering state s. Offline rule
     compilation — mirrors loading Snort rules into the regex accelerator.
+
+    States are the trie's. The dense fill goes one depth at a time: a
+    state's failure row (complete, as it lies shallower) is copied, then
+    its own trie edges are set over it.
     """
-    patterns = [p.encode() if isinstance(p, str) else bytes(p) for p in patterns]
-    # Trie build.
-    goto = [{}]
-    out = [0]
-    for pat in patterns:
-        s = 0
-        for ch in pat:
-            if ch not in goto[s]:
-                goto.append({})
-                out.append(0)
-                goto[s][ch] = len(goto) - 1
-            s = goto[s][ch]
-        out[s] += 1
-    # BFS failure links -> dense DFA.
-    n = len(goto)
-    fail = [0] * n
+    parent, edge, depth, out = trie(patterns)
+    n = parent.size
+    fail = np.zeros(n, np.int64)
     table = np.zeros((n, 256), dtype=np.int32)
-    from collections import deque
-    q = deque()
-    for ch in range(256):
-        nxt = goto[0].get(ch, 0)
-        table[0, ch] = nxt
-        if nxt:
-            fail[nxt] = 0
-            q.append(nxt)
-    while q:
-        s = q.popleft()
-        out[s] += out[fail[s]]
-        for ch in range(256):
-            if ch in goto[s]:
-                nxt = goto[s][ch]
-                fail[nxt] = table[fail[s], ch]
-                table[s, ch] = nxt
-                q.append(nxt)
-            else:
-                table[s, ch] = table[fail[s], ch]
-    return table, np.asarray(out, dtype=np.int32)
+    by_depth = np.argsort(depth, kind="stable")
+    bounds = np.searchsorted(depth[by_depth], np.arange(depth.max() + 3))
+    for d in range(depth.max() + 1):
+        level = by_depth[bounds[d]:bounds[d + 1]]
+        if d >= 2:
+            fail[level] = table[fail[parent[level]], edge[level]]
+        if d >= 1:
+            out[level] += out[fail[level]]
+            table[level] = table[fail[level]]
+        kids = by_depth[bounds[d + 1]:bounds[d + 2]]
+        table[parent[kids], edge[kids]] = kids
+    return table, out.astype(np.int32)
 
 
 def dfa_scan(payload: jnp.ndarray, length: jnp.ndarray, table: jnp.ndarray,

@@ -12,6 +12,10 @@ and enqueue). It does two things:
     process-wide record per name: cumulative ns and calls, and the start
     and length of the most recent ``HISTORY`` calls.
 
+``set_gauge(name, value)`` keeps one process-wide number per name, the last
+value set (the regex accelerator's compiled automaton: its states and the
+bytes it holds on the device); ``gauges()`` returns them all.
+
 ``totals()`` returns the cumulative record as plain dicts. Like the
 orchestrator's ``fast_stats`` the totals only go up, so callers read them
 by deltas. ``between(name, lo_ns, hi_ns)`` sums the recent calls that
@@ -49,6 +53,7 @@ class _Record:
 
 
 _RECORDS: Dict[str, _Record] = {}
+_GAUGES: Dict[str, float] = {}
 _LOCK = threading.Lock()
 
 
@@ -80,6 +85,18 @@ class span:
             rec.dur[i] = ns
             rec.ns += ns
             rec.calls += 1
+
+
+def set_gauge(name: str, value: float) -> None:
+    """Set the process-wide gauge ``name`` to ``value``."""
+    with _LOCK:
+        _GAUGES[name] = float(value)
+
+
+def gauges() -> Dict[str, float]:
+    """``{name: value}`` of every gauge set so far."""
+    with _LOCK:
+        return dict(_GAUGES)
 
 
 def totals() -> Dict[str, Dict[str, int]]:

@@ -138,19 +138,19 @@ def test_aho_corasick_counts():
     assert int(n[0]) == 3                       # she, he, hers
 
 
-@pytest.mark.parametrize("B,L,block_b", [(4, 64, 2), (8, 96, 4), (2, 128, 2)])
-def test_dfa_kernel_vs_ref(B, L, block_b):
-    table, out = ref.build_aho_corasick(["abc", "cab", "bbb"])
+@pytest.mark.parametrize("B,L", [(4, 64), (8, 96), (2, 128)])
+def test_dfa_kernel_vs_ref(B, L):
+    rules = ["abc", "cab", "bbb"]
+    table, out = ref.build_aho_corasick(rules)
     pay = jnp.asarray(RNG.integers(97, 100, size=(B, L)).astype(np.uint8))
     length = jnp.asarray(RNG.integers(1, L + 1, size=(B,)), jnp.int32)
     want = ref.dfa_scan(pay, length, jnp.asarray(table), jnp.asarray(out))
-    got = ops.regex_scan(pay, length, table, out, impl="interpret",
-                         block_b=block_b)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    match, _, _ = ops.regex_matcher(rules, impl="interpret")
+    np.testing.assert_array_equal(np.asarray(match(pay, length)),
+                                  np.asarray(want))
 
 
 def test_dfa_kernel_table_over_256_states():
-    """Past 256 states the kernel splits the table into two bf16 planes."""
     pats = ["".join(chr(97 + int(c)) for c in RNG.integers(0, 4, size=6))
             for _ in range(80)]
     table, out = ref.build_aho_corasick(pats)
@@ -158,8 +158,9 @@ def test_dfa_kernel_table_over_256_states():
     pay = jnp.asarray(RNG.integers(97, 101, size=(16, 200)).astype(np.uint8))
     length = jnp.asarray(RNG.integers(1, 201, size=(16,)), jnp.int32)
     want = ref.dfa_scan(pay, length, jnp.asarray(table), jnp.asarray(out))
-    got = ops.regex_scan(pay, length, table, out, impl="interpret", block_b=8)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    match, _, _ = ops.regex_matcher(pats, impl="interpret")
+    np.testing.assert_array_equal(np.asarray(match(pay, length)),
+                                  np.asarray(want))
     assert int(want.sum()) > 0
 
 
