@@ -14,6 +14,9 @@ else:
   ingress rings -> run the full stage chain once over all lanes -> gather
   the egress back to original packet order.
 
+The ingress batch's copy to the device is issued at hand-over, before the
+host pass, and runs while the TO classifies.
+
 ``M`` is the per-pipeline sub-batch slot count, padded up to a power-of-two
 bucket so the set of compiled shapes stays small and bounded (recompiles are
 counted in ``dispatch_stats`` — zero in steady state). Rings are allocated
@@ -83,6 +86,24 @@ class PipelineRunner:
 # One fused dispatch program per stage chain, shared by every data plane in
 # the process (jax.jit caches per-shape specializations underneath).
 _DISPATCH_PROGRAMS: Dict[Any, Callable] = {}
+
+# Host arrays go to the device through a jitted call's C++ argument path,
+# which issues each copy asynchronously for tens of microseconds of host
+# time; jax.device_put's Python path costs ~0.8 ms a batch on a v5e host.
+# The argument is donated, so the output aliases the fresh device buffer
+# and the program does no work on the device.
+_to_device = jax.jit(lambda arrays: arrays, donate_argnums=0)
+
+
+def _stage(batch: PacketBatch) -> PacketBatch:
+    """``batch`` with its host leaves put on the device (copies issued, not
+    awaited); leaves already on the device are kept, never donated."""
+    leaves, tree = jax.tree.flatten(batch)
+    host = [i for i, a in enumerate(leaves) if not isinstance(a, jax.Array)]
+    if host:
+        for i, a in zip(host, _to_device([leaves[i] for i in host])):
+            leaves[i] = a
+    return jax.tree.unflatten(tree, leaves)
 
 
 def _dispatch_program(app: MeiliApp) -> Callable:
@@ -188,9 +209,10 @@ class ParallelDataPlane:
 
     # -- persistent stacked rings ---------------------------------------------
     def _ensure_rings(self, batch: PacketBatch, M: int) -> None:
-        proto = jax.tree.map(lambda a: a[0], batch)
-        proto_key = tuple((tuple(a.shape), str(a.dtype))
-                          for a in jax.tree.leaves(proto))
+        # The row layout comes from shapes and dtypes alone: the batch is on
+        # the device, and slicing a row out of it would launch an op a leaf.
+        proto_key = tuple((tuple(a.shape[1:]), str(a.dtype))
+                          for a in jax.tree.leaves(batch))
         lanes = len(self.to.pipelines)
         if (self._rings is None or M > self._ring_cap
                 or lanes != self._ring_lanes
@@ -200,6 +222,8 @@ class ParallelDataPlane:
             # cap divides 2^32.
             self._ring_cap = _bucket(max(self.ring_capacity, M))
             self._ring_lanes = lanes
+            proto = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), batch)
             self._rings = make_rings(proto, self._ring_cap, lanes)
             self._ring_proto_key = proto_key
 
@@ -230,13 +254,20 @@ class ParallelDataPlane:
     # -- partition -> fused dispatch -> aggregate ------------------------------
     def process(self, batch: PacketBatch,
                 tenant: Optional[str] = None) -> PacketBatch:
+        # Start the batch's host->device copy at hand-over: the dispatch
+        # gathers its lanes on the device, so the copy needs nothing the TO
+        # decides and crosses while the TO classifies. The TO keeps the host
+        # batch (it reads the 5-tuple on the host, where a device array
+        # would force a sync).
+        with span("meili.dispatch.stage"):
+            staged = _stage(batch)
         assign = self.to.partition_assign(batch, tenant=tenant)
         proc = np.nonzero(assign >= 0)[0]      # halted-flow packets buffered
         self._tag_tenant(tenant, proc.size)
         if proc.size == 0:
             return self._empty_result(batch)
         with span("meili.dispatch.index"):
-            padded, *index = self._index(batch, assign, proc)
+            padded, *index = self._index(staged, assign, proc)
         self.dispatch_stats["calls"] += 1
         before = self._dispatch._cache_size()
         with span("meili.dispatch.enqueue"):
@@ -273,9 +304,10 @@ class ParallelDataPlane:
 
     def _index(self, batch: PacketBatch, assign: np.ndarray,
                proc: np.ndarray) -> Tuple:
-        """The dispatch's arguments after the rings: the ingress batch
-        padded to its bucket, then ``perm``, ``counts`` and ``out_idx``
-        (host arrays, copied to the device by the dispatch call)."""
+        """The dispatch's arguments after the rings: the device-resident
+        ingress batch padded to its bucket, then ``perm``, ``counts`` and
+        ``out_idx`` (host arrays, copied to the device by the dispatch
+        call)."""
         lanes_of = assign[proc]
         N = len(self.to.pipelines)
         counts = np.bincount(lanes_of, minlength=N).astype(np.int32)

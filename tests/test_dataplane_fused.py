@@ -1,4 +1,6 @@
 """Fused data plane: semantics oracle, compile-cache behavior, stacked rings."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,6 +59,58 @@ def test_fused_oracle_with_migration_active():
     assert_batches_equal(dp.process(PKTS), oracle)
 
 
+# -- the ingress batch's early copy -------------------------------------------
+
+def spy_on_dispatch(dp):
+    """Wrap ``dp``'s dispatch program; returns the batches it is given."""
+    seen, dispatch = [], dp._dispatch
+
+    def spy(rings, batch, *index):
+        seen.append(batch)
+        return dispatch(rings, batch, *index)
+
+    spy._cache_size = dispatch._cache_size
+    dp._dispatch = spy
+    return seen
+
+
+@pytest.mark.parametrize("size", [256, 300])     # 300 pads to 512 rows
+def test_a_host_batch_reaches_the_dispatch_on_the_device(size):
+    pk = synth_packets(batch=size, num_flows=24, pkt_bytes=128, seed=size)
+    host = jax.tree.map(np.asarray, pk)
+    dp = ParallelDataPlane(ALL_APPS(impl="ref")["FW"], num_pipelines=3,
+                           capacity_per_pipeline=128)
+    seen = spy_on_dispatch(dp)
+    assert dp.process(host).batch == size
+    [staged] = seen
+    leaves = jax.tree.leaves(staged)
+    assert leaves and all(isinstance(a, jax.Array) for a in leaves)
+    assert staged.batch == _bucket(size)
+
+
+@pytest.mark.parametrize("size", [256, 300])
+@pytest.mark.parametrize("name", ["ID", "FW"])
+def test_a_host_batch_equals_the_batch_put_first_and_the_unfused_path(
+        name, size):
+    app = ALL_APPS(impl="ref")[name]
+    pk = synth_packets(batch=size, num_flows=24, pkt_bytes=128, seed=size)
+    host = jax.tree.map(np.asarray, pk)
+
+    def plane():
+        return ParallelDataPlane(app, num_pipelines=3,
+                                 capacity_per_pipeline=64)
+
+    got = plane().process(host)
+    assert got.batch == size
+    assert_batches_equal(got, plane().process(jax.device_put(host)))
+    assert_batches_equal(got, plane().process_unfused(host))
+    assert_batches_equal(got, run_pipeline(app, pk))
+    # Leaves already on the device are used as they are, never donated.
+    mixed = dataclasses.replace(host, payload=jax.device_put(host.payload))
+    assert_batches_equal(got, plane().process(mixed))
+    assert not mixed.payload.is_deleted()
+
+
 # -- compile-cache behavior ---------------------------------------------------
 
 def test_zero_steady_state_recompiles():
@@ -91,7 +145,8 @@ def test_no_recompiles_after_warmup_via_cache_counters():
 def test_dataplane_metrics_and_stage_profile():
     """With a metrics registry attached, dispatch calls/compiles and
     per-stage device timings land as labeled series; each dispatch is one
-    ``meili.dispatch.index`` span and one ``meili.dispatch.enqueue`` span."""
+    ``meili.dispatch.stage``, one ``meili.dispatch.index`` and one
+    ``meili.dispatch.enqueue`` span."""
     from repro.obs import Obs, spans
 
     obs = Obs()
@@ -99,7 +154,8 @@ def test_dataplane_metrics_and_stage_profile():
     dp = ParallelDataPlane(app, num_pipelines=2, capacity_per_pipeline=32,
                            metrics=obs.metrics)
     before = {n: spans.totals().get(n, {"calls": 0})["calls"]
-              for n in ("meili.dispatch.index", "meili.dispatch.enqueue")}
+              for n in ("meili.dispatch.stage", "meili.dispatch.index",
+                        "meili.dispatch.enqueue")}
     for _ in range(3):
         dp.process(PKTS)
     calls = obs.metrics.get("dataplane_dispatch_calls_total", app=app.name)

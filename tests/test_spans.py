@@ -1,5 +1,5 @@
 """Phase spans of the data plane (``repro.obs.spans``): the helper's record,
-its annotations in a profiler trace, and the eight spans that one
+its annotations in a profiler trace, and the nine spans that one
 ``ParallelDataPlane.process`` call advances."""
 import glob
 import itertools
@@ -14,7 +14,8 @@ from repro.obs import spans
 
 PHASES = ("meili.to.assign", "meili.to.flow_ids", "meili.to.probe",
           "meili.to.miss_loop", "meili.to.commit", "meili.to.maintain",
-          "meili.dispatch.index", "meili.dispatch.enqueue")
+          "meili.dispatch.stage", "meili.dispatch.index",
+          "meili.dispatch.enqueue")
 # Without a flow cache there is no probe.
 SLOW_PHASES = tuple(n for n in PHASES if n != "meili.to.probe")
 
